@@ -33,17 +33,31 @@ SDS = jax.ShapeDtypeStruct
 
 _MEASURED_BW = None
 
+#: Published HBM bandwidth (bytes/s) per TPU ``device_kind``.  Source:
+#: Google Cloud TPU documentation, system architecture pages ("TPU v5e":
+#: 819 GB/s HBM2 per chip).
+HBM_BW_BY_KIND = {
+    "TPU v5 lite": analytic.HBM_BW,
+}
+
 
 def stream_bandwidth() -> float:
     """Achievable streaming memory bandwidth (bytes/s) on the machine the
-    benchmarks actually run on: ``analytic.HBM_BW`` on TPU, otherwise
+    benchmarks actually run on: on a TPU, the published HBM rate of its
+    ``device_kind`` (:data:`HBM_BW_BY_KIND`; a kind missing from the
+    table raises rather than borrowing another chip's rate); otherwise
     measured once by streaming large uint32 arrays through a bitwise op —
     the same instruction mix the word-space kernels execute, so the bound
     is what THIS machine could do with zero non-memory overhead.
     Memoized; the probe costs ~100 ms."""
     global _MEASURED_BW
     if jax.default_backend() == "tpu":
-        return analytic.HBM_BW
+        kind = jax.devices()[0].device_kind
+        if kind not in HBM_BW_BY_KIND:
+            raise ValueError(
+                f"no published HBM bandwidth for TPU device_kind {kind!r}; "
+                f"known: {sorted(HBM_BW_BY_KIND)}")
+        return HBM_BW_BY_KIND[kind]
     if _MEASURED_BW is None:
         import time
 

@@ -51,7 +51,9 @@ def main() -> None:
                     help="run only the multi-process serve-plane smoke "
                          "(bit-identity + wire-compression gates) and exit")
     args, _ = ap.parse_known_args()
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.dist_smoke:
         dist_smoke()
 

@@ -21,12 +21,10 @@ import jax.numpy as jnp
 
 from .ewah import FULL, MAX_CLEAN, MAX_DIRTY  # noqa: F401  (shared constants)
 
-_FULL = jnp.uint32(0xFFFFFFFF)
-
 
 def classify(words: jax.Array) -> jax.Array:
     """0 = clean-0, 1 = clean-1, 2 = dirty."""
-    return jnp.where(words == 0, 0, jnp.where(words == _FULL, 1, 2)).astype(jnp.int32)
+    return jnp.where(words == 0, 0, jnp.where(words == FULL, 1, 2)).astype(jnp.int32)
 
 
 def _run_ids(kind: jax.Array):
@@ -127,8 +125,21 @@ def compressed_size(words: jax.Array, capacity: int = 0):
 
 @partial(jax.jit, static_argnames=("n_words",))
 def decompress(stream: jax.Array, length, n_words: int):
-    """Expand an EWAH stream into n_words uint32 words (scan-based)."""
-    C = stream.shape[0]
+    """Expand EWAH streams into n_words uint32 words each (scan-based).
+
+    ``stream`` is (..., C) with ``length`` (...) live words per stream;
+    returns (..., n_words).  One scan walks all streams in step and gives
+    every stream word the output position where it starts.  Positions
+    never decrease along a stream, so with each stream offset into its own
+    row of one flat buffer, the scatters that place dirty words and
+    clean-1 run bounds are a single sorted scatter-add each (a word that
+    writes nothing adds 0).  Sorted and flat, they compile for a TPU in
+    about a second; vmapped per stream, the TPU compiler took tens of
+    seconds per query program."""
+    lead, C = stream.shape[:-1], stream.shape[-1]
+    words = stream.reshape(-1, C).T               # (C, K): scan over C
+    length = jnp.reshape(length, (-1,))
+    K = words.shape[1]
 
     def step(carry, w):
         i, dirty_rem, out_pos = carry
@@ -137,28 +148,34 @@ def decompress(stream: jax.Array, length, n_words: int):
         ctype = (w >> 31) & 1
         nclean = ((w >> 15) & 0xFFFF).astype(jnp.int32)
         ndirty = (w & 0x7FFF).astype(jnp.int32)
-        # dirty word event
-        dw_pos = jnp.where(active & is_dirty, out_pos, n_words)
         # marker event: clean run [out_pos, out_pos + nclean)
         mk = active & ~is_dirty
-        c_start = jnp.where(mk & (ctype == 1), out_pos, n_words)
         c_len = jnp.where(mk, nclean, 0)
         new_out = out_pos + jnp.where(is_dirty, 1, c_len)
         new_dirty = jnp.where(is_dirty, dirty_rem - 1, jnp.where(mk, ndirty, 0))
-        return (i + 1, new_dirty, new_out), (dw_pos, w, c_start, c_len)
+        dirty_word = jnp.where(active & is_dirty, w, jnp.uint32(0))
+        clean1 = (mk & (ctype == 1)).astype(jnp.int32)
+        return (i + 1, new_dirty, new_out), (out_pos, dirty_word, clean1, c_len)
 
-    (_, _, final_pos), (dpos, dval, c1s, clen) = jax.lax.scan(
-        step, (jnp.int32(0), jnp.int32(0), jnp.int32(0)), stream)
-    out = jnp.zeros(n_words + 1, jnp.uint32)
-    out = out.at[dpos].set(dval, mode="drop")
+    zero = jnp.zeros(K, jnp.int32)
+    _, (start, dval, c1, clen) = jax.lax.scan(
+        step, (jnp.int32(0), zero, zero), words)  # each (C, K)
+    # row k owns flat slots [k * (n_words + 1), (k + 1) * (n_words + 1));
+    # its last slot collects whatever lands past the end and is dropped
+    row = jnp.arange(K, dtype=jnp.int32) * (n_words + 1)
+    pos = (jnp.minimum(start, n_words) + row).T.reshape(-1)
+    end = (jnp.minimum(start + clen, n_words) + row).T.reshape(-1)
+    flat = K * (n_words + 1)
+    out = jnp.zeros(flat, jnp.uint32).at[pos].add(
+        dval.T.reshape(-1), indices_are_sorted=True)
     # clean-1 region fill via +1/-1 events and cumsum
-    ev = jnp.zeros(n_words + 1, jnp.int32)
-    ev = ev.at[c1s].add(1, mode="drop")
-    c1e = jnp.where(c1s < n_words, c1s + clen, n_words + 1)
-    ev = ev.at[c1e].add(-1, mode="drop")
-    infull = jnp.cumsum(ev[:-1]) > 0
-    out = jnp.where(infull, _FULL, out[:-1])
-    return out
+    c1 = c1.T.reshape(-1)
+    ev = (jnp.zeros(flat, jnp.int32)
+          .at[pos].add(c1, indices_are_sorted=True)
+          .at[end].add(-c1, indices_are_sorted=True))
+    out, ev = out.reshape(K, n_words + 1), ev.reshape(K, n_words + 1)
+    infull = jnp.cumsum(ev[:, :-1], axis=1) > 0
+    return jnp.where(infull, FULL, out[:, :-1]).reshape(*lead, n_words)
 
 
 def logical_op(stream_a, len_a, stream_b, len_b, n_words: int, op: str, capacity: int):

@@ -1139,7 +1139,7 @@ class JaxBackend:
     carry canonically numbered leaves, so structurally equal plans share one
     root tuple and hence one compiled program with a correct leaf mapping.
     Each group's leaf streams pad into one (B, m, C) uint32 batch and
-    decompress via a doubly-vmapped ``ewah_jax.decompress``.  With
+    decompress in one batched ``ewah_jax.decompress``.  With
     ``fuse=True`` (the default) the whole op tree THEN runs as one Pallas
     megakernel launch: the plan root lowers to a static instruction tape
     (:func:`lower_plan`) that ``kernels.ops.plan_fuse`` interprets in
@@ -1156,12 +1156,31 @@ class JaxBackend:
 
     def __init__(self, use_kernel: bool = True, interpret=None,
                  cache_size: int = 256, fuse: bool = True):
+        from ..kernels.ops import resolve_interpret
+
         self.use_kernel = use_kernel
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.fuse = fuse
         self._jit_cache: dict = {}
         self._tape_memo: dict = {}
         self.result_cache = ResultCache(cache_size)
+        self._paths_mutex = make_lock("jax_backend_paths", reentrant=False)
+        self._paths = {"fused": 0, "staged": 0,  # guarded-by: _paths_mutex
+                       "host_reencode": 0}
+
+    def group_paths(self) -> dict:
+        """Plan groups run per path since construction: ``fused`` (one
+        megakernel tape), ``staged`` (per-stage kernels), and of either,
+        ``host_reencode`` (a compressed result past ``MAX_DIRTY`` words,
+        re-encoded on the host)."""
+        with self._paths_mutex:
+            return dict(self._paths)
+
+    def _count_path(self, root, host_reencode: bool = False) -> None:
+        path = "staged" if self._fused_tape(root) is None else "fused"
+        with self._paths_mutex:
+            self._paths[path] += 1
+            self._paths["host_reencode"] += host_reencode
 
     def execute(self, plan: Plan):
         return self.execute_many([plan])[0]
@@ -1177,6 +1196,7 @@ class JaxBackend:
             n_words = (n_rows + ewah.WORD_BITS - 1) // ewah.WORD_BITS
             fn = self._compiled(root, cap, n_words)
             words = np.asarray(fn(jnp.asarray(batch), jnp.asarray(lengths)))
+            self._count_path(root)
             for b, i in enumerate(idxs):
                 bits = ewah.unpack_bits(words[b], n_rows)
                 out[i] = (np.flatnonzero(bits), plans[i].leaf_words())
@@ -1211,6 +1231,7 @@ class JaxBackend:
         for (root, cap, n_rows), idxs in self._group(plans, todo).items():
             batch, lengths = self._pad_group(plans, idxs, cap)
             n_words = (n_rows + ewah.WORD_BITS - 1) // ewah.WORD_BITS
+            self._count_path(root, host_reencode=n_words > ewah.MAX_DIRTY)
             if n_words <= ewah.MAX_DIRTY:
                 fn = self._compiled(root, cap, n_words, compressed=True)
                 streams, lens = fn(jnp.asarray(batch), jnp.asarray(lengths))
@@ -1359,20 +1380,25 @@ class JaxBackend:
         use_kernel, interpret = self.use_kernel, self.interpret
 
         def run(batch, lengths):  # (B, m, C), (B, m) -> (B, W)
-            dec = jax.vmap(jax.vmap(
-                lambda s, l: ewah_jax.decompress(s, l, n_words)))(batch, lengths)
-
             if tape is not None:
                 # fused: the whole op tree + recompress classification in
-                # ONE megakernel launch over the flattened batch
-                B, m = dec.shape[0], dec.shape[1]
-                planes = dec.transpose(1, 0, 2).reshape(m, -1)  # (m, B*W)
+                # ONE megakernel launch over the flattened batch.  Leaves
+                # decode straight into (m, B, lane-aligned width) rows, so
+                # flattening to the kernel's (m, B*width) planes is a view
+                # (an odd width such as MAX_DIRTY made the TPU compiler
+                # spend half a minute per program relayouting it); the
+                # padding words are clean-0 and are cut off afterwards
+                B, m = batch.shape[0], batch.shape[1]
+                width = -(-n_words // 128) * 128
+                dec = ewah_jax.decompress(batch.transpose(1, 0, 2),
+                                          lengths.T, width)  # (m, B, width)
                 flat, kflat = kops.plan_fuse(
-                    planes, tape, use_kernel=use_kernel, interpret=interpret)
-                words = flat.reshape(B, n_words)
+                    dec.reshape(m, -1), tape, use_kernel=use_kernel,
+                    interpret=interpret)
+                words = flat.reshape(B, width)[:, :n_words]
                 if not compressed:
                     return words
-                kind = kflat.reshape(B, n_words)
+                kind = kflat.reshape(B, width)[:, :n_words]
                 # per-row run starts from the fused classification: word 0
                 # always opens a run (recompress_batch's opposite-class
                 # sentinel reduces to exactly this), then any class change
@@ -1383,6 +1409,8 @@ class JaxBackend:
                 return jax.vmap(
                     lambda w, k, s: ewah_jax.compress_from_runs(
                         w, k, s, n_words + 1))(words, kind, start)
+
+            dec = ewah_jax.decompress(batch, lengths, n_words)  # (B, m, W)
 
             def ev(node):
                 if node[0] == "leaf":

@@ -24,7 +24,8 @@ coordinator + N worker processes, one segment-subset per worker:
 * **Execution.**  The coordinator fans a query batch out to every owner
   (all sends first, then all receives — workers compute in parallel),
   each worker executes its segments' plans through the existing backends
-  (numpy, or jax with megakernel fusion) and replies with **compressed**
+  (numpy, or jax with ``interpret=True``: workers run on the CPU, since
+  the chip belongs to the coordinator) and replies with **compressed**
   :meth:`~repro.core.ewah_stream.EwahStream.to_bytes` result streams —
   results are never densified for transport.  The coordinator evaluates
   the open buffer densely (it owns those rows), stitches per-segment
@@ -256,7 +257,9 @@ class ServePlane:
         src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
         env = dict(os.environ)
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # a chip belongs to one process: the coordinator may hold it, so
+        # workers never try to claim it
+        env["JAX_PLATFORMS"] = "cpu"
         try:
             for rank in range(self.n_hosts):
                 self._procs.append(subprocess.Popen(
@@ -415,6 +418,12 @@ class ServePlane:
         """Mirror of ``SegmentedIndex._execute_many`` with the per-segment
         execution fanned out across worker processes; returns
         ``(segments, buffer, triples)`` against one synced snapshot."""
+        if backend == "jax" and backend_opts.get("interpret") is not True:
+            raise ValueError(
+                "serve-plane workers are CPU processes and cannot share the "
+                "coordinator's accelerator: backend='jax' runs there only as "
+                "the Pallas interpreter, so it needs an explicit "
+                "interpret=True")
         preds = list(preds)
         with self._lock:
             if self._closed:

@@ -20,11 +20,20 @@ ROW_TILE = 256  # 8 words of 32 rows
 LANE_TILE = 128
 
 
-def _kernel(bits_ref, words_ref):
-    bits = bits_ref[...].astype(jnp.uint32)  # (ROW_TILE, LANE_TILE)
-    b = bits.reshape(ROW_TILE // 32, 32, LANE_TILE)
+def pack32(bits: jax.Array) -> jax.Array:
+    """(R, L) 0/1 uint32 -> (R/32, L) uint32, bit j of word w = row 32w+j.
+
+    The shifted bits are disjoint, so their sum is their OR.  Mosaic
+    reduces signed integers only, so the sum runs in int32 (wrapping into
+    bit 31 is exact) and the result is bitcast back."""
+    b = bits.reshape(bits.shape[0] // 32, 32, bits.shape[1])
     shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 32, 1), 1)
-    words_ref[...] = (b << shifts).sum(axis=1, dtype=jnp.uint32)
+    summed = jax.lax.bitcast_convert_type(b << shifts, jnp.int32).sum(axis=1)
+    return jax.lax.bitcast_convert_type(summed, jnp.uint32)
+
+
+def _kernel(bits_ref, words_ref):
+    words_ref[...] = pack32(bits_ref[...].astype(jnp.uint32))
 
 
 def bitpack_kernel(bits: jax.Array, *, interpret: bool = True) -> jax.Array:

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .bitpack import pack32
+
 ROW_TILE = 256
 LANE_TILE = 128
 
@@ -29,9 +31,7 @@ def _kernel(eids_ref, words_ref, *, k: int):
     hit = jnp.zeros((ROW_TILE, LANE_TILE), jnp.uint32)
     for i in range(k):  # k is small and static (4 or 8)
         hit |= (eids[:, i : i + 1] == ecol).astype(jnp.uint32)
-    h = hit.reshape(ROW_TILE // 32, 32, LANE_TILE)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 32, 1), 1)
-    words_ref[...] = (h << shifts).sum(axis=1, dtype=jnp.uint32)
+    words_ref[...] = pack32(hit)
 
 
 def moe_route_kernel(eids: jax.Array, n_experts: int, *, interpret: bool = True):

@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handle padding to tile boundaries, choose interpret mode automatically
-(True off-TPU so the kernels validate on CPU), and expose a ``use_kernel``
-switch falling back to the jnp reference implementation.
+Handle padding to tile boundaries, resolve ``interpret=None`` through
+:func:`resolve_interpret` (compiled on a TPU, interpreted elsewhere so the
+kernels validate on CPU), and expose a ``use_kernel`` switch falling back
+to the jnp reference implementation.
 """
 
 from __future__ import annotations
@@ -24,8 +25,13 @@ from .slicefold import slicefold_kernel
 from .wordops import wordops_kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """The one place ``interpret=None`` is decided: compiled Pallas on a
+    TPU, the interpreter on any other backend (so the kernels validate on
+    the CPU).  An explicit True/False is kept as given."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 def _pad_to(x, mult, axis, value=0):
@@ -44,7 +50,7 @@ def bitpack(bits, use_kernel=True, interpret=None):
     R, C = bits.shape
     if not use_kernel:
         return ref.bitpack(_pad_to(bits, 32, 0))[: -(-R // 32)]
-    interpret = not _on_tpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     x = _pad_to(_pad_to(bits, ROW_TILE, 0), LANE_TILE, 1)
     out = bitpack_kernel(x, interpret=interpret)
     return out[: -(-R // 32), :C]
@@ -56,7 +62,7 @@ def wordops(a, b, op="and", use_kernel=True, interpret=None):
     n = a.shape[0]
     if not use_kernel:
         return ref.wordops(a, b, op)
-    interpret = not _on_tpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     lanes = 128
     rows = -(-n // lanes)
     from .wordops import ROW_TILE as RT
@@ -105,7 +111,7 @@ def container_pairs(a, b, op="and", use_kernel=True, interpret=None):
         if op == "or":
             return a | b
         return a & ~b
-    interpret = not _on_tpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     from .containers import LANE_TILE as LT
     from .containers import ROW_TILE as RT
     from .containers import containerops_kernel
@@ -135,7 +141,7 @@ def container_gallop(positions, words, use_kernel=True, interpret=None):
     if not use_kernel:
         hits = (gathered >> (safe & 31).astype(jnp.uint32)) & jnp.uint32(1)
     else:
-        interpret = not _on_tpu() if interpret is None else interpret
+        interpret = resolve_interpret(interpret)
         from .containers import LANE_TILE as LT
         from .containers import ROW_TILE as RT
         from .containers import member_kernel
@@ -172,7 +178,7 @@ def slice_fold(stacked, ops, use_kernel=True, interpret=None):
         for i, op in enumerate(ops):
             r = fns[op](r, stacked[i + 1])
         return r
-    interpret = not _on_tpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     lanes = 128
     from .slicefold import ROW_TILE as RT
     rows = -(-n // lanes)
@@ -218,7 +224,7 @@ def plan_fuse(stacked, tape, use_kernel=True, interpret=None):
                 stack.append(fn(a, b))
         r = stack.pop()
         return r, ewah_jax.classify(r)
-    interpret = not _on_tpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     lanes = 128
     rows = -(-n // lanes)
     rows_p = -(-rows // RT) * RT
@@ -247,7 +253,7 @@ def recompress_batch(words, capacity, use_kernel=True, interpret=None):
     sent = jnp.where(words[:, :1] == 0, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
     prev = jnp.concatenate([sent, words[:, :-1]], axis=1)
     if use_kernel:
-        interpret = not _on_tpu() if interpret is None else interpret
+        interpret = resolve_interpret(interpret)
         lanes = 128
         from .recompress import ROW_TILE as RT
         n = B * W
@@ -282,7 +288,7 @@ def gray(x, inverse=False, use_kernel=True, interpret=None):
     n = x.shape[0]
     if not use_kernel:
         return ref.gray(x, inverse)
-    interpret = not _on_tpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     lanes = 128
     from .gray import ROW_TILE as RT
     rows = -(-n // lanes)
@@ -297,7 +303,7 @@ def histogram(vals, n_values, use_kernel=True, interpret=None):
     """int32 values -> (n_values,) float32 counts."""
     if not use_kernel:
         return ref.histmm(vals, n_values)
-    interpret = not _on_tpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     n = vals.shape[0]
     v_pad = -(-n_values // VAL_TILE) * VAL_TILE
     # pad tokens with an out-of-range value -> lands in a padded count slot
@@ -318,7 +324,7 @@ def moe_route_bitmap(eids, n_experts, use_kernel=True, interpret=None):
     T, k = eids.shape
     if not use_kernel:
         return ref.moe_route(eids, n_experts)
-    interpret = not _on_tpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     from .moe_route import LANE_TILE as LT, ROW_TILE as RT
     e_pad = -(-n_experts // LT) * LT
     t_pad = (-T) % RT

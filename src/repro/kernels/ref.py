@@ -5,8 +5,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-FULL = jnp.uint32(0xFFFFFFFF)
-
 
 def bitpack(bits: jax.Array) -> jax.Array:
     """(R, C) bool/int -> (ceil(R/32), C) uint32 (zero-padded rows)."""
@@ -23,7 +21,7 @@ def wordops(a, b, op="and"):
     fn = {"and": jnp.bitwise_and, "or": jnp.bitwise_or,
           "xor": jnp.bitwise_xor}[op]
     r = fn(a, b)
-    cls = jnp.where(r == 0, 0, jnp.where(r == FULL, 1, 2)).astype(jnp.int32)
+    cls = jnp.where(r == 0, 0, jnp.where(r == jnp.uint32(0xFFFFFFFF), 1, 2)).astype(jnp.int32)
     return r, cls
 
 

@@ -21,7 +21,6 @@ from jax.experimental import pallas as pl
 
 ROW_TILE = 64
 LANE_TILE = 128
-FULL = jnp.uint32(0xFFFFFFFF)
 
 _OPS = {"and": 0, "or": 1, "xor": 2}
 

@@ -5,7 +5,7 @@ requests together minimizes padding waste.  We sort the admission queue by
 (length-bin frequency, length) — Gray-Frequency (paper §4.2) applied to the
 serving plane: popular length classes form dense runs and batches.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b --smoke
+  PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b [--no-smoke]
 """
 
 from __future__ import annotations
@@ -26,6 +26,7 @@ from repro.core.lifecycle import BackgroundCompactor
 from repro.core.query import PLAN_STATS
 from repro.dist.sharding import (batch_shardings, cache_shardings,
                                  param_shardings, replicated)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_cli_mesh
 from repro.models import transformer
 from repro.models.common import ShardingCtx
@@ -290,9 +291,16 @@ def padding_waste(lengths, batches):
 
 
 def main(argv=None):
+    """Serve ``--requests`` synthetic requests; returns a summary dict
+    (``requests``, ``answered``: distinct requests that were packed and
+    decoded, ``tokens``, ``finite``: every prefill's logits finite,
+    ``seconds``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the architecture's reduced smoke config "
+                         "(--no-smoke: its published widths)")
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--gen-tokens", type=int, default=16)
@@ -339,7 +347,11 @@ def main(argv=None):
                          "so compaction's cost model starts warm with last "
                          "run's predicate mix, save at exit")
     args = ap.parse_args(argv)
+    if args.hosts >= 2 and args.query_backend == "jax":
+        ap.error("--hosts workers are CPU processes that cannot share the "
+                 "accelerator; use --query-backend numpy with --hosts")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
@@ -404,6 +416,7 @@ def main(argv=None):
                     else nullcontext())
         t0 = time.time()
         generated = 0
+        finite, tok = jnp.bool_(True), None
         with trace_cm:
             for bi, idx in enumerate(batches):
                 b = len(idx)
@@ -425,6 +438,7 @@ def main(argv=None):
                     logits, cache = prefill(params, jnp.asarray(prompts))
                     if args.profile:
                         jax.block_until_ready(cache)
+                finite &= jnp.isfinite(logits).all()
                 tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
                 cache_len = jnp.int32(prompt_len)
                 generated += b
@@ -435,7 +449,11 @@ def main(argv=None):
                             jax.block_until_ready(tok)
                     cache_len += 1
                     generated += b
+            # the clock stops when the last token exists, not when the
+            # last step was enqueued
+            jax.block_until_ready((finite, tok))
     dt = time.time() - t0
+    answered = len(np.unique(np.concatenate(batches))) if batches else 0
     print(f"served {len(lengths)} requests, {generated} tokens "
           f"in {dt:.1f}s ({generated/dt:.1f} tok/s)")
     if args.profile:
@@ -450,6 +468,8 @@ def main(argv=None):
         WORKLOAD_STATS.save(args.workload_stats)
         print(f"workload-stats saved to {args.workload_stats}: "
               f"{WORKLOAD_STATS.stats()}")
+    return {"requests": len(lengths), "answered": answered,
+            "tokens": generated, "finite": bool(finite), "seconds": dt}
 
 
 if __name__ == "__main__":

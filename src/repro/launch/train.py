@@ -28,6 +28,7 @@ from repro.data.tokens import TokenPipeline
 from repro.dist import checkpoint as ckpt
 from repro.dist.sharding import (batch_shardings, opt_shardings,
                                  param_shardings, zero_pad_for)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_cli_mesh
 from repro.models import transformer
 from repro.models.common import ShardingCtx
@@ -81,8 +82,8 @@ class StragglerMonitor:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
-    ap.add_argument("--smoke", action="store_true", default=True)
-    ap.add_argument("--no-smoke", dest="smoke", action="store_false")
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -99,6 +100,7 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

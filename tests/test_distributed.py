@@ -24,6 +24,7 @@ from repro.configs import get_config
 from repro.dist.sharding import (batch_shardings, cache_shardings,
                                  opt_shardings, param_shardings,
                                  zero_pad_for)
+from repro.launch.mesh import make_debug_mesh
 from repro.models import transformer
 from repro.models.common import ShardingCtx
 from repro.optim import OptConfig, init_opt_state
@@ -31,7 +32,7 @@ from repro.train import train_step
 from functools import partial
 
 results = {}
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_debug_mesh(4, 2)
 cfg = get_config("tinyllama-1.1b").smoke()
 
 with ShardingCtx(mesh):

@@ -115,3 +115,17 @@ def test_kernel_feeds_ewah_pipeline():
         stream = ewah.compress(words[:, c])
         back = ewah.decompress(stream)
         np.testing.assert_array_equal(back, words[:, c])
+
+
+def test_resolve_interpret_follows_the_backend():
+    """``interpret=None`` means compiled on a TPU and interpreted anywhere
+    else; an explicit choice is kept; the jax query backend resolves it
+    once, at construction, through the same helper."""
+    from repro.core.query import JaxBackend
+
+    on_tpu = jax.default_backend() == "tpu"
+    assert ops.resolve_interpret() is (not on_tpu)
+    assert ops.resolve_interpret(True) is True
+    assert ops.resolve_interpret(False) is False
+    assert JaxBackend().interpret is (not on_tpu)
+    assert JaxBackend(interpret=False).interpret is False

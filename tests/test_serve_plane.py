@@ -151,14 +151,19 @@ def test_eight_host_lifecycle_bit_identity():
 
 
 def test_two_host_jax_fused_bit_identity():
-    """The jax backend (megakernel fusion on) runs inside workers and
-    still merges bit-identically with the numpy reference."""
+    """The jax backend (megakernel fusion on) runs inside workers, in the
+    Pallas interpreter the caller asked for, and still merges
+    bit-identically with the numpy reference; without an explicit
+    ``interpret=True`` the plane refuses it."""
     clock = [T0]
     ref = build_writer(lambda: clock[0], n_per=96)
     with ServePlane(build_writer(lambda: clock[0], n_per=96),
                     n_hosts=2) as plane:
+        with pytest.raises(ValueError, match="interpret=True"):
+            plane.query_many(PREDS, backend="jax", now=clock[0])
         want = ref.index.query_many(PREDS, backend="numpy", now=clock[0])
-        got = plane.query_many(PREDS, backend="jax", now=clock[0])
+        got = plane.query_many(PREDS, backend="jax", interpret=True,
+                               now=clock[0])
         for (wr, _), (gr, _) in zip(want, got):
             np.testing.assert_array_equal(wr, gr)
 
