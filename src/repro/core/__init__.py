@@ -6,7 +6,7 @@ seal / compact lifecycle) -> Segment / SegmentedIndex -> predicate algebra
 the seal-once convenience over the writer."""
 
 from . import (column_order, encoding, encodings, ewah, ewah_stream,
-               histogram, index_size, query, sorting, strategies)
+               histogram, index_size, query, sorting, strategies, trace)
 from .bitmap_index import BitmapIndex, assign_codes, index_size_report
 from .ewah_stream import EwahStream
 from .lifecycle import (BackgroundCompactor, IndexWriter, compact,
@@ -44,6 +44,7 @@ __all__ = [
     "query",
     "sorting",
     "strategies",
+    "trace",
 ]
 
 # import-cycle note: segment/lifecycle import bitmap_index at module level;

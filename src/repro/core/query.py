@@ -51,7 +51,7 @@ from functools import lru_cache
 
 import numpy as np
 
-from . import ewah, ewah_stream
+from . import ewah, ewah_stream, trace
 from .ewah_stream import EwahStream
 from ..analysis.runtime import make_lock, maybe_validate
 
@@ -1210,45 +1210,65 @@ class JaxBackend:
         group exactly like ``execute_many``, but the compiled program ends
         with the in-graph recompression stage (Pallas classify/run-start
         kernel + vmapped scan/scatter emit), so results come back as EWAH
-        streams, whole-plan results land in ``result_cache``."""
+        streams, whole-plan results land in ``result_cache``.
+
+        Each group adds ``padded_words`` (B·m·C), ``leaf_words`` (the live
+        words of its leaf streams) and ``shipped_bytes`` (what goes to the
+        device) to the request's counters (:mod:`repro.core.trace`)."""
+        with trace.request("execute_compressed_many"):
+            return self._execute_compressed_many(plans)
+
+    def _execute_compressed_many(self, plans):
+        import jax
         import jax.numpy as jnp
 
-        plans = [lower_containers(p, self._container_fold,
-                                  self.result_cache) for p in plans]
-        out: list = [None] * len(plans)
-        keys: list = [None] * len(plans)
-        todo = []
-        for i, p in enumerate(plans):
-            digests = [_leaf_digest(s) for s in p.streams]
-            keys[i] = _node_key(p.root, digests, p.n_rows)
-            hit = self.result_cache.get(keys[i])
-            if hit is not None:
-                out[i] = maybe_validate(
-                    EwahStream(hit.data, hit.n_rows, 0),  # cache: no scan
-                    origin="JaxBackend.execute_compressed_many[cache]")
-            else:
-                todo.append(i)
-        for (root, cap, n_rows), idxs in self._group(plans, todo).items():
-            batch, lengths = self._pad_group(plans, idxs, cap)
+        with trace.span("query.lookup"):
+            plans = [lower_containers(p, self._container_fold,
+                                      self.result_cache) for p in plans]
+            out: list = [None] * len(plans)
+            keys: list = [None] * len(plans)
+            todo = []
+            for i, p in enumerate(plans):
+                digests = [_leaf_digest(s) for s in p.streams]
+                keys[i] = _node_key(p.root, digests, p.n_rows)
+                hit = self.result_cache.get(keys[i])
+                if hit is not None:
+                    out[i] = maybe_validate(
+                        EwahStream(hit.data, hit.n_rows, 0),  # cache: no scan
+                        origin="JaxBackend.execute_compressed_many[cache]")
+                else:
+                    todo.append(i)
+            groups = self._group(plans, todo)
+        for (root, cap, n_rows), idxs in groups.items():
+            with trace.span("query.pad"):
+                batch, lengths = self._pad_group(plans, idxs, cap)
+            trace.add("padded_words", batch.size)
+            trace.add("leaf_words", lengths.sum())
+            trace.add("shipped_bytes", batch.nbytes + lengths.nbytes)
             n_words = (n_rows + ewah.WORD_BITS - 1) // ewah.WORD_BITS
             self._count_path(root, host_reencode=n_words > ewah.MAX_DIRTY)
-            if n_words <= ewah.MAX_DIRTY:
-                fn = self._compiled(root, cap, n_words, compressed=True)
-                streams, lens = fn(jnp.asarray(batch), jnp.asarray(lengths))
-                streams, lens = np.asarray(streams), np.asarray(lens)
-                enc = [streams[b, : lens[b]] for b in range(len(idxs))]
-            else:
+            on_device = n_words <= ewah.MAX_DIRTY
+            with trace.span("query.dispatch"):
                 # beyond the single-marker-per-group limit of the vectorized
                 # emit (~1M rows) the re-encode happens host-side
-                fn = self._compiled(root, cap, n_words)
-                words = np.asarray(fn(jnp.asarray(batch), jnp.asarray(lengths)))
-                enc = [ewah.compress(words[b]) for b in range(len(idxs))]
-            for b, i in enumerate(idxs):
-                res = maybe_validate(
-                    EwahStream(enc[b], n_rows, plans[i].leaf_words()),
-                    origin="JaxBackend.execute_compressed_many")
-                self.result_cache.put(keys[i], res, plans[i].scope)
-                out[i] = res
+                fn = self._compiled(root, cap, n_words, compressed=on_device)
+                got = fn(jnp.asarray(batch), jnp.asarray(lengths))
+            with trace.span("query.device_wait"):
+                jax.block_until_ready(got)
+            with trace.span("query.readback"):
+                if on_device:
+                    streams, lens = np.asarray(got[0]), np.asarray(got[1])
+                    enc = [streams[b, : lens[b]] for b in range(len(idxs))]
+                else:
+                    words = np.asarray(got)
+                    enc = [ewah.compress(words[b]) for b in range(len(idxs))]
+            with trace.span("query.results"):
+                for b, i in enumerate(idxs):
+                    res = maybe_validate(
+                        EwahStream(enc[b], n_rows, plans[i].leaf_words()),
+                        origin="JaxBackend.execute_compressed_many")
+                    self.result_cache.put(keys[i], res, plans[i].scope)
+                    out[i] = res
         return out
 
     def _group(self, plans, idxs=None) -> dict:
@@ -1390,27 +1410,33 @@ class JaxBackend:
                 # padding words are clean-0 and are cut off afterwards
                 B, m = batch.shape[0], batch.shape[1]
                 width = -(-n_words // 128) * 128
-                dec = ewah_jax.decompress(batch.transpose(1, 0, 2),
-                                          lengths.T, width)  # (m, B, width)
-                flat, kflat = kops.plan_fuse(
-                    dec.reshape(m, -1), tape, use_kernel=use_kernel,
-                    interpret=interpret)
+                with jax.named_scope("decode"):
+                    dec = ewah_jax.decompress(  # (m, B, width)
+                        batch.transpose(1, 0, 2), lengths.T, width)
+                with jax.named_scope("evaluate"):
+                    flat, kflat = kops.plan_fuse(
+                        dec.reshape(m, -1), tape, use_kernel=use_kernel,
+                        interpret=interpret)
                 words = flat.reshape(B, width)[:, :n_words]
                 if not compressed:
                     return words
-                kind = kflat.reshape(B, width)[:, :n_words]
-                # per-row run starts from the fused classification: word 0
-                # always opens a run (recompress_batch's opposite-class
-                # sentinel reduces to exactly this), then any class change
-                first = jnp.ones((B, 1), jnp.int32)
-                start = jnp.concatenate(
-                    [first, (kind[:, 1:] != kind[:, :-1]).astype(jnp.int32)],
-                    axis=1)
-                return jax.vmap(
-                    lambda w, k, s: ewah_jax.compress_from_runs(
-                        w, k, s, n_words + 1))(words, kind, start)
+                with jax.named_scope("recompress"):
+                    kind = kflat.reshape(B, width)[:, :n_words]
+                    # per-row run starts from the fused classification: word
+                    # 0 always opens a run (recompress_batch's opposite-class
+                    # sentinel reduces to exactly this), then any class
+                    # change
+                    first = jnp.ones((B, 1), jnp.int32)
+                    start = jnp.concatenate(
+                        [first,
+                         (kind[:, 1:] != kind[:, :-1]).astype(jnp.int32)],
+                        axis=1)
+                    return jax.vmap(
+                        lambda w, k, s: ewah_jax.compress_from_runs(
+                            w, k, s, n_words + 1))(words, kind, start)
 
-            dec = ewah_jax.decompress(batch, lengths, n_words)  # (B, m, W)
+            with jax.named_scope("decode"):
+                dec = ewah_jax.decompress(batch, lengths, n_words)  # (B, m, W)
 
             def ev(node):
                 if node[0] == "leaf":
@@ -1439,13 +1465,16 @@ class JaxBackend:
                     use_kernel=use_kernel, interpret=interpret)
                 return folded.reshape(parts.shape[1:])
 
-            words = ev(root)
+            with jax.named_scope("evaluate"):
+                words = ev(root)
             if not compressed:
                 return words
             # worst-case EWAH size for n words is n + 1 (all-dirty: one
             # marker + n verbatim words; clean groups only shrink it)
-            return kops.recompress_batch(
-                words, n_words + 1, use_kernel=use_kernel, interpret=interpret)
+            with jax.named_scope("recompress"):
+                return kops.recompress_batch(
+                    words, n_words + 1, use_kernel=use_kernel,
+                    interpret=interpret)
 
         fn = jax.jit(run)
         self._jit_cache[key] = fn

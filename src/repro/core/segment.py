@@ -63,11 +63,10 @@ from __future__ import annotations
 import itertools
 import time
 from dataclasses import dataclass, field
-from time import perf_counter
 
 import numpy as np
 
-from . import ewah, ewah_stream
+from . import ewah, ewah_stream, trace
 from ..analysis.runtime import maybe_validate
 from .bitmap_index import BitmapIndex, _observe_workload
 from .ewah_stream import EwahStream, concat_streams
@@ -486,8 +485,9 @@ class SegmentedIndex:
         batch across predicates and segments on the jax backend).  The open
         buffer evaluates densely over its uncompressed columns and its
         result stream concatenates after the sealed segments."""
-        _, _, triples = self._execute_many(preds, backend, names,
-                                           backend_opts, now)
+        with trace.request("execute_compressed_many"):
+            _, _, triples = self._execute_many(preds, backend, names,
+                                               backend_opts, now)
         return [(per_seg, merged) for per_seg, _, merged in triples]
 
     def _execute_many(self, preds, backend, names, backend_opts, now=None):
@@ -497,24 +497,25 @@ class SegmentedIndex:
         predicate.  Tombstoned/expired rows are excluded everywhere: each
         sealed plan root is ANDed with its segment's live mask (one extra
         merge), buffer rows mask densely."""
-        segs, buf = self._snapshot()
-        self._check(segs, buf is not None)
-        now = self._now(now)
-        names = names if names is not None else self.names
-        be = get_backend(backend, **backend_opts)
-        live = [s.live_stream(now) if s.n_rows else None for s in segs]
-        active = [j for j, s in enumerate(segs) if s.n_rows]
-        plans = []
-        for p in preds:
-            for j in active:
-                plan = compile_plan(segs[j].index, p, names=names)
-                plans.append(with_live_mask(plan, live[j]))
-        t0 = perf_counter()
-        if hasattr(be, "execute_compressed_many"):
-            results = be.execute_compressed_many(plans)
-        else:
-            results = [be.execute_compressed(p) for p in plans]
-        _observe_workload(plans, perf_counter() - t0)
+        with trace.span("query.plan"):
+            segs, buf = self._snapshot()
+            self._check(segs, buf is not None)
+            now = self._now(now)
+            names = names if names is not None else self.names
+            be = get_backend(backend, **backend_opts)
+            live = [s.live_stream(now) if s.n_rows else None for s in segs]
+            active = [j for j, s in enumerate(segs) if s.n_rows]
+            plans = []
+            for p in preds:
+                for j in active:
+                    plan = compile_plan(segs[j].index, p, names=names)
+                    plans.append(with_live_mask(plan, live[j]))
+        with trace.span("query.backend") as call:
+            if hasattr(be, "execute_compressed_many"):
+                results = be.execute_compressed_many(plans)
+            else:
+                results = [be.execute_compressed(p) for p in plans]
+        _observe_workload(plans, call.seconds)
         total_rows = (sum(s.n_rows for s in segs)
                       + (len(buf[1]) if buf is not None else 0))
         out = []
@@ -528,18 +529,21 @@ class SegmentedIndex:
             scanned = sum(r.words_scanned for r in per_seg)
             buf_rows = None
             if buf is not None:
-                cols, bdel, bexp = buf
-                # dense one-pass evaluation; scan cost is the buffer's
-                # dense word count
-                mask = evaluate_mask(pred, cols, names=names)
-                mask &= ~bdel & (bexp > now)
-                buf_rows = np.flatnonzero(mask)
-                words = ewah.positions_to_words(buf_rows, len(mask))
-                parts.append(ewah.compress(words))
-                scanned += len(words)
-            merged = (EwahStream(concat_streams(parts), total_rows, scanned)
-                      if parts else EwahStream(empty, 0, 0))
-            maybe_validate(merged, origin="SegmentedIndex._execute_many")
+                with trace.span("query.buffer"):
+                    cols, bdel, bexp = buf
+                    # dense one-pass evaluation; scan cost is the buffer's
+                    # dense word count
+                    mask = evaluate_mask(pred, cols, names=names)
+                    mask &= ~bdel & (bexp > now)
+                    buf_rows = np.flatnonzero(mask)
+                    words = ewah.positions_to_words(buf_rows, len(mask))
+                    parts.append(ewah.compress(words))
+                    scanned += len(words)
+            with trace.span("query.concat"):
+                merged = (EwahStream(concat_streams(parts), total_rows,
+                                     scanned)
+                          if parts else EwahStream(empty, 0, 0))
+                maybe_validate(merged, origin="SegmentedIndex._execute_many")
             out.append((per_seg, buf_rows, merged))
         return segs, buf, out
 
@@ -553,18 +557,28 @@ class SegmentedIndex:
     def query_many(self, preds, backend: str = "numpy", names=None,
                    now=None, **backend_opts):
         """Batched queries; one (row_ids, words_scanned) per predicate."""
-        segs, _, triples = self._execute_many(preds, backend, names,
-                                              backend_opts, now)
-        buf_start = segs[-1].row_stop if segs else 0
-        out = []
-        for per_seg, buf_rows, merged in triples:
-            ids = [seg.original_rows(r.to_rows())
-                   for seg, r in zip(segs, per_seg) if seg.n_rows]
-            if buf_rows is not None:
-                ids.append(buf_start + buf_rows)
-            rows = (np.sort(np.concatenate(ids)) if ids
-                    else np.asarray([], dtype=np.int64))
-            out.append((rows, merged.words_scanned))
+        with trace.request("query_many"):
+            segs, _, triples = self._execute_many(preds, backend, names,
+                                                  backend_opts, now)
+            buf_start = segs[-1].row_stop if segs else 0
+            out = []
+            for per_seg, buf_rows, merged in triples:
+                ids = []
+                # one segment at a time, so each segment's local row ids
+                # are freed as soon as they are mapped
+                for seg, r in zip(segs, per_seg):
+                    if seg.n_rows:
+                        with trace.span("query.to_rows"):
+                            rows = r.to_rows()
+                        with trace.span("query.map_ids"):
+                            rows = seg.original_rows(rows)
+                        ids.append(rows)
+                if buf_rows is not None:
+                    ids.append(buf_start + buf_rows)
+                with trace.span("query.sort"):
+                    rows = (np.sort(np.concatenate(ids)) if ids
+                            else np.asarray([], dtype=np.int64))
+                out.append((rows, merged.words_scanned))
         return out
 
     def count(self, pred, backend: str = "numpy", names=None, now=None,
@@ -572,7 +586,9 @@ class SegmentedIndex:
         """Matching live-row count without materializing ids (compressed-
         domain popcount of the merged stream; tombstoned and expired rows
         are already ANDed out)."""
-        _, merged = self.execute_compressed(pred, backend=backend,
-                                            names=names, now=now,
-                                            **backend_opts)
-        return merged.count()
+        with trace.request("count"):
+            _, merged = self.execute_compressed(pred, backend=backend,
+                                                names=names, now=now,
+                                                **backend_opts)
+            with trace.span("query.count"):
+                return merged.count()

@@ -21,7 +21,7 @@ import numpy as np
 
 from repro.analysis.runtime import make_lock
 from repro.configs import get_config
-from repro.core import BitmapIndex, Eq, IndexSpec, IndexWriter
+from repro.core import BitmapIndex, Eq, IndexSpec, IndexWriter, trace
 from repro.core.lifecycle import BackgroundCompactor
 from repro.core.query import PLAN_STATS
 from repro.dist.sharding import (batch_shardings, cache_shardings,
@@ -266,12 +266,14 @@ class PhaseProfile:
 
     @contextmanager
     def span(self, name: str):
-        t0 = time.perf_counter()
+        """The phase as a :class:`repro.core.trace.span` named
+        ``serve.<name>``, its seconds added to ``name``."""
+        s = trace.span(f"serve.{name}")
         try:
-            yield
+            with s:
+                yield
         finally:
-            self.acc[name] = (self.acc.get(name, 0.0)
-                              + time.perf_counter() - t0)
+            self.acc[name] = self.acc.get(name, 0.0) + s.seconds
 
     def report(self, total: float | None = None) -> None:
         tot = total or sum(self.acc.values()) or 1.0

@@ -9,6 +9,7 @@ the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,6 +126,30 @@ def test_jax_backend_group_program_compiles(one_chip, fuse):
     hlo = fn.lower(_spec(one_chip, (SEGMENTS, m, cap), jnp.uint32),
                    _spec(one_chip, (SEGMENTS, m), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "per_stage"])
+def test_group_program_ops_carry_their_stage_scope(one_chip, fuse):
+    """Every stage of the group program names its ops: ``decode``,
+    ``evaluate`` and ``recompress`` sit in the ops' ``op_name`` paths,
+    which the profiler's device ops carry, and the fused kernel keeps its
+    instruction name ``plan_fuse.<n>``."""
+    rng = np.random.default_rng(1)
+    cols = [rng.integers(0, c, size=4096) for c in (7, 2526)]
+    idx = BitmapIndex.build(cols, IndexSpec(encoding="auto"))
+    plan = compile_plan(idx, And(Range(1, 100, 1400), Not(Eq(0, 3))))
+    be = JaxBackend(interpret=False, fuse=fuse)
+    m, cap = len(plan.streams), N_WORDS + 1
+    fn = be._compiled(plan.root, cap, N_WORDS, compressed=True)
+    hlo = fn.lower(_spec(one_chip, (SEGMENTS, m, cap), jnp.uint32),
+                   _spec(one_chip, (SEGMENTS, m), jnp.int32)).compile().as_text()
+    for scope in ("decode", "evaluate", "recompress"):
+        assert re.search(rf'op_name="[^"]*/{scope}/', hlo), scope
+    kernel = re.search(r'%(plan_fuse(\.\d+)?) = [^\n]*op_name="([^"]*)"', hlo)
+    if fuse:
+        assert kernel and "/evaluate/" in kernel.group(3)
+    else:
+        assert kernel is None
 
 
 def test_stream_bandwidth_is_keyed_by_device_kind(monkeypatch):
