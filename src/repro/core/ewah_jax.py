@@ -123,19 +123,62 @@ def compressed_size(words: jax.Array, capacity: int = 0):
     return n_groups + n_dirty
 
 
+def _shift(x, s: int, fill, up: bool):
+    """Move ``x`` along axis 0 by ``s`` rows, filling the rows it leaves.
+
+    ``up`` moves row p + s to row p (toward lower indices), else row p - s
+    to row p.  A static pad with one negative edge: no gather."""
+    edge = (-s, s, 0) if up else (s, -s, 0)
+    return jax.lax.pad(x, jnp.asarray(fill, x.dtype),
+                       [edge] + [(0, 0, 0)] * (x.ndim - 1))
+
+
+def _route(key, carried, dist, bits, up: bool):
+    """One pass of a log-step shift network along axis 0.
+
+    A word (a row where ``key >= 0``) moves by ``2**b`` at the stage of
+    bit ``b`` where that bit of ``dist(key, carried)`` is set; ``bits``
+    gives the stages in order.  The ``carried`` arrays travel with their
+    words."""
+    for b in bits:
+        s = 1 << b
+        d = dist(key, carried)
+        mv = (key >= 0) & ((d & s) != 0)
+        arrive = _shift(mv, s, False, up)
+        key = jnp.where(arrive, _shift(key, s, -1, up), jnp.where(mv, -1, key))
+        carried = tuple(jnp.where(arrive, _shift(c, s, 0, up), c)
+                        for c in carried)
+    return key, carried
+
+
 @partial(jax.jit, static_argnames=("n_words",))
 def decompress(stream: jax.Array, length, n_words: int):
-    """Expand EWAH streams into n_words uint32 words each (scan-based).
+    """Expand EWAH streams into n_words uint32 words each, scatter-free.
 
     ``stream`` is (..., C) with ``length`` (...) live words per stream;
     returns (..., n_words).  One scan walks all streams in step and gives
-    every stream word the output position where it starts.  Positions
-    never decrease along a stream, so with each stream offset into its own
-    row of one flat buffer, the scatters that place dirty words and
-    clean-1 run bounds are a single sorted scatter-add each (a word that
-    writes nothing adds 0).  Sorted and flat, they compile for a TPU in
-    about a second; vmapped per stream, the TPU compiler took tens of
-    seconds per query program."""
+    every stream word the output slot where it starts.  The words that
+    write something are kept: dirty words with a nonzero value and
+    clean-1 markers with nclean > 0 (clean-0 runs and empty runs are the
+    zeros the output starts as).  Along each stream, a shift network of
+    static shifts and selects routes them to their slots, with no scatter
+    and no gather:
+
+    * compaction: each kept word moves left by the number of dropped words
+      before it, one bit of that count per stage, least significant first;
+    * expansion: it moves right from its rank to its slot, most
+      significant bit first.
+
+    Both displacements never decrease along a stream, so no two words meet
+    at any stage: for kept words i < j, the gap between them after a stage
+    is (j - i) less the part of (d_j - d_i) routed so far, which is at most
+    the dropped words between them in compaction (gap >= 1), and in
+    expansion the slots' high bits routed so far never decrease (gap >=
+    j - i).  Kept slots are strictly increasing (a dirty word takes one
+    slot, a kept marker nclean > 0), so every word lands on a slot of its
+    own.  A clean-1 marker carries its run length; the running maximum of
+    the run ends along the stream then fills each run with ones.  Words
+    that land at or past n_words are dropped."""
     lead, C = stream.shape[:-1], stream.shape[-1]
     words = stream.reshape(-1, C).T               # (C, K): scan over C
     length = jnp.reshape(length, (-1,))
@@ -160,22 +203,33 @@ def decompress(stream: jax.Array, length, n_words: int):
     zero = jnp.zeros(K, jnp.int32)
     _, (start, dval, c1, clen) = jax.lax.scan(
         step, (jnp.int32(0), zero, zero), words)  # each (C, K)
-    # row k owns flat slots [k * (n_words + 1), (k + 1) * (n_words + 1));
-    # its last slot collects whatever lands past the end and is dropped
-    row = jnp.arange(K, dtype=jnp.int32) * (n_words + 1)
-    pos = (jnp.minimum(start, n_words) + row).T.reshape(-1)
-    end = (jnp.minimum(start + clen, n_words) + row).T.reshape(-1)
-    flat = K * (n_words + 1)
-    out = jnp.zeros(flat, jnp.uint32).at[pos].add(
-        dval.T.reshape(-1), indices_are_sorted=True)
-    # clean-1 region fill via +1/-1 events and cumsum
-    c1 = c1.T.reshape(-1)
-    ev = (jnp.zeros(flat, jnp.int32)
-          .at[pos].add(c1, indices_are_sorted=True)
-          .at[end].add(-c1, indices_are_sorted=True))
-    out, ev = out.reshape(K, n_words + 1), ev.reshape(K, n_words + 1)
-    infull = jnp.cumsum(ev[:, :-1], axis=1) > 0
-    return jnp.where(infull, FULL, out[:, :-1]).reshape(*lead, n_words)
+    # every stream is a column of (rows, K); a kept word carries
+    # key = slot * 2 + clean-1 tag (-1 where no word is) and its payload:
+    # the dirty value, or the clean-1 run length
+    run1 = (c1 != 0) & (clen > 0)
+    keep = ((dval != 0) | run1) & (start < n_words)
+    key = jnp.where(keep, start * 2 + c1, -1)
+    pay = jnp.where(run1, clen.astype(jnp.uint32), dval)
+    keep = keep.astype(jnp.int32)
+    rank = jnp.cumsum(keep, axis=0) - keep
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, K), 0)
+    key, (pay, _) = _route(key, (pay, row - rank), lambda k, c: c[1],
+                           range((C - 1).bit_length()), up=True)
+    # compacted: the kept words sit in rows [0, kept) < n_words
+    if C >= n_words:
+        key, pay = key[:n_words], pay[:n_words]
+    else:
+        pad = [(0, n_words - C), (0, 0)]
+        key = jnp.pad(key, pad, constant_values=-1)
+        pay = jnp.pad(pay, pad)
+    row = jax.lax.broadcasted_iota(jnp.int32, (n_words, K), 0)
+    key, (pay,) = _route(key, (pay,), lambda k, c: (k >> 1) - row,
+                         reversed(range((n_words - 1).bit_length())), up=False)
+    full = (key >= 0) & ((key & 1) == 1)
+    end = jnp.where(full, row + pay.astype(jnp.int32), 0)
+    full = jax.lax.cummax(end, axis=0) > row
+    out = jnp.where(full, FULL, jnp.where(key >= 0, pay, jnp.uint32(0)))
+    return out.T.reshape(*lead, n_words)
 
 
 def logical_op(stream_a, len_a, stream_b, len_b, n_words: int, op: str, capacity: int):

@@ -110,12 +110,10 @@ def test_moe_route_bitmap_compiles(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "per_stage"])
-def test_jax_backend_group_program_compiles(one_chip, fuse):
-    """The whole group program a query runs on the device: decode every
-    leaf stream, evaluate the plan (one megakernel, or wordops_fold and
-    slice_fold per stage), re-encode the results in the graph."""
-    rng = np.random.default_rng(0)
+def _group_program_hlo(one_chip, fuse, seed):
+    """HLO text of the jax backend's group program for a two-column filter,
+    compiled over SEGMENTS segments of N_WORDS words."""
+    rng = np.random.default_rng(seed)
     cols = [rng.integers(0, c, size=4096) for c in (7, 2526)]
     idx = BitmapIndex.build(cols, IndexSpec(encoding="auto"))
     plan = compile_plan(idx, And(Range(1, 100, 1400), Not(Eq(0, 3))))
@@ -123,9 +121,16 @@ def test_jax_backend_group_program_compiles(one_chip, fuse):
     assert (be._fused_tape(plan.root) is not None) == fuse
     m, cap = len(plan.streams), N_WORDS + 1
     fn = be._compiled(plan.root, cap, N_WORDS, compressed=True)
-    hlo = fn.lower(_spec(one_chip, (SEGMENTS, m, cap), jnp.uint32),
-                   _spec(one_chip, (SEGMENTS, m), jnp.int32)).compile().as_text()
-    assert "tpu_custom_call" in hlo
+    return fn.lower(_spec(one_chip, (SEGMENTS, m, cap), jnp.uint32),
+                    _spec(one_chip, (SEGMENTS, m), jnp.int32)).compile().as_text()
+
+
+@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "per_stage"])
+def test_jax_backend_group_program_compiles(one_chip, fuse):
+    """The whole group program a query runs on the device: decode every
+    leaf stream, evaluate the plan (one megakernel, or wordops_fold and
+    slice_fold per stage), re-encode the results in the graph."""
+    assert "tpu_custom_call" in _group_program_hlo(one_chip, fuse, seed=0)
 
 
 @pytest.mark.parametrize("fuse", [True, False], ids=["fused", "per_stage"])
@@ -134,15 +139,7 @@ def test_group_program_ops_carry_their_stage_scope(one_chip, fuse):
     ``evaluate`` and ``recompress`` sit in the ops' ``op_name`` paths,
     which the profiler's device ops carry, and the fused kernel keeps its
     instruction name ``plan_fuse.<n>``."""
-    rng = np.random.default_rng(1)
-    cols = [rng.integers(0, c, size=4096) for c in (7, 2526)]
-    idx = BitmapIndex.build(cols, IndexSpec(encoding="auto"))
-    plan = compile_plan(idx, And(Range(1, 100, 1400), Not(Eq(0, 3))))
-    be = JaxBackend(interpret=False, fuse=fuse)
-    m, cap = len(plan.streams), N_WORDS + 1
-    fn = be._compiled(plan.root, cap, N_WORDS, compressed=True)
-    hlo = fn.lower(_spec(one_chip, (SEGMENTS, m, cap), jnp.uint32),
-                   _spec(one_chip, (SEGMENTS, m), jnp.int32)).compile().as_text()
+    hlo = _group_program_hlo(one_chip, fuse, seed=1)
     for scope in ("decode", "evaluate", "recompress"):
         assert re.search(rf'op_name="[^"]*/{scope}/', hlo), scope
     kernel = re.search(r'%(plan_fuse(\.\d+)?) = [^\n]*op_name="([^"]*)"', hlo)
@@ -150,6 +147,24 @@ def test_group_program_ops_carry_their_stage_scope(one_chip, fuse):
         assert kernel and "/evaluate/" in kernel.group(3)
     else:
         assert kernel is None
+
+
+@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "per_stage"])
+def test_group_program_decode_has_no_scatter(one_chip, fuse):
+    """The decode places stream words with static shifts and selects: no
+    op of the compiled group program under ``/decode/`` is a scatter
+    (recompression keeps its own)."""
+    hlo = _group_program_hlo(one_chip, fuse, seed=2)
+    # a scatter, fused or not, keeps the jax op it came from at the end of
+    # its op_name path (".../decode/jit(decompress)/scatter-add")
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+
+    def scatters(stage):
+        return [n for n in names if f"/{stage}/" in n
+                and n.rsplit("/", 1)[-1].startswith("scatter")]
+
+    assert scatters("recompress"), "the check sees no scatter at all"
+    assert not scatters("decode")
 
 
 def test_stream_bandwidth_is_keyed_by_device_kind(monkeypatch):
